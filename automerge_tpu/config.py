@@ -13,10 +13,31 @@ Padding fields exist because XLA compiles per shape: a fixed `op_pad` /
 of similar size, no recompilation storm — SURVEY §7 "padding + bucketing").
 """
 
+import os
 from dataclasses import dataclass, replace
 from typing import Optional
 
 import numpy as np
+
+_CHECKOUT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+
+def use_compile_cache():
+    """Turn on JAX's persistent compilation cache for this process —
+    the one helper every entry point (tests, ``bench.py``,
+    ``chip_smoke.py``) calls.
+
+    Where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX reads it itself and
+    no directory is named here. Otherwise the cache lives at
+    ``<checkout>/.jax_cache``: a fixed path, since the path is part of
+    the cache key. Compiles under half a second are not written.
+    Returns the directory in use."""
+    import jax
+    if not os.environ.get('JAX_COMPILATION_CACHE_DIR'):
+        jax.config.update('jax_compilation_cache_dir',
+                          os.path.join(_CHECKOUT, '.jax_cache'))
+    jax.config.update('jax_persistent_cache_min_compile_time_secs', 0.5)
+    return jax.config.jax_compilation_cache_dir
 
 
 @dataclass(frozen=True)

@@ -491,7 +491,9 @@ def _pack_docs(works, options, job_of=None, m_pad=0):
 
 def _resolve_batch(arrays, n_segs, options):
     """Assignment-only resolution (pallas-eligible dispatch)."""
-    resolve = _engine.pick_resolve_kernel(options.kernel)
+    seg_id, clock = arrays[0], arrays[3]
+    resolve = _engine.pick_resolve_kernel(
+        options.kernel, (seg_id.shape[0], seg_id.shape[1], clock.shape[2]))
     out = resolve(*(jnp.asarray(a) for a in arrays), num_segments=n_segs)
     return np.asarray(out['surviving'])
 

@@ -54,8 +54,9 @@ def _pallas_wins(n_docs, n_ops, n_actors):
             and n_docs >= 256)
 
 
-def pick_resolve_kernel(kernel='auto'):
-    """Select the field-resolution kernel implementation.
+def pick_resolve_kernel(kernel, shape):
+    """Select the field-resolution kernel for a batch of ``shape`` =
+    (docs, ops, actors).
 
     'xla'    — segment-reduction path (merge.py), runs everywhere.
     'pallas' — hand-scheduled VMEM-resident kernel (pallas_merge.py);
@@ -65,19 +66,8 @@ def pick_resolve_kernel(kernel='auto'):
                `_pallas_wins`), xla otherwise and on non-TPU backends.
     """
     if kernel == 'auto':
-        if jax.default_backend() != 'tpu':
-            return merge_kernel.resolve_assignments_batch
-
-        def dispatch(seg_id, actor, seq, clock, is_del, valid, *, num_segments):
-            if _pallas_wins(seg_id.shape[0], seg_id.shape[1],
-                            clock.shape[2]):
-                from . import pallas_merge
-                fn = pallas_merge.resolve_assignments_batch_pallas
-            else:
-                fn = merge_kernel.resolve_assignments_batch
-            return fn(seg_id, actor, seq, clock, is_del, valid,
-                      num_segments=num_segments)
-        return dispatch
+        kernel = 'pallas' if (jax.default_backend() == 'tpu'
+                              and _pallas_wins(*shape)) else 'xla'
     if kernel == 'pallas':
         from . import pallas_merge
         return pallas_merge.resolve_assignments_batch_pallas
@@ -166,12 +156,13 @@ def batch_merge_docs(docs_changes, return_timing=False, kernel=None,
     n_segs = opts.pad_segments(max((p.n_segments for p in packed), default=1))
     t1 = time.perf_counter()
 
-    resolve = pick_resolve_kernel(opts.kernel)
+    resolve = pick_resolve_kernel(
+        opts.kernel, (seg_id.shape[0], seg_id.shape[1], clock.shape[2]))
     from . import profiler as _profiler
     _profiler.note_dispatch(
         'engine.resolve',
-        (getattr(resolve, '__name__', 'resolve'), seg_id.shape,
-         clock.shape, str(seg_id.dtype), str(clock.dtype), n_segs),
+        (resolve.__name__, seg_id.shape, clock.shape, str(seg_id.dtype),
+         str(clock.dtype), n_segs),
         rows=seg_id.shape[0])
     out = resolve(
         jnp.asarray(seg_id), jnp.asarray(actor), jnp.asarray(seq),

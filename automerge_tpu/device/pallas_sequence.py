@@ -26,9 +26,15 @@ terminator, which changes nothing for valid on-chain nodes (tree_pos is
 anchored to the head's distance) — vis_index/length are bit-identical to
 `vmap(_rga_order)`; tree_pos of PADDING rows differs and is not emitted.
 
-Layout: node axis padded to a multiple of 128 lanes (m <= 512 is the
-intended regime, matching the MXU variant's dispatch bound); jobs ride
-the grid in blocks of 8.
+Layout: node axis padded to one 128-lane tile; jobs ride the grid in
+blocks of 8, walked by a ``fori_loop`` inside the kernel (unrolled, the
+8-job body took 15-25 s to compile for v5e; looped, ~5 s). On a TPU
+the kernel is bounded to m <= 128 (``MAX_M``): its log-shifted
+concatenates do not lower past the first lane tile (Mosaic: "Input
+offsets outside of the first tile" at m = 512), and a variant with the
+shifts as matmuls did not finish compiling at m = 256 within 300 s.
+Wider trees ride the XLA variants. Interpret mode (CPU tests) takes
+any m.
 
 Why the one-hot schedules STOP at m ~= 512 (measured, r5): every
 doubling round must materialize a [*, m, m] one-hot — O(m^2) VPU
@@ -58,6 +64,8 @@ from .sequence import _ceil_log2
 
 JOB_BLOCK = 8
 NODE_TILE = 128
+# widest node axis the kernel compiles at for a TPU (module docstring)
+MAX_M = NODE_TILE
 
 
 def _make_kernel(m, rounds):
@@ -74,12 +82,13 @@ def _make_kernel(m, rounds):
                visidx_ref, len_ref):
         iota_r = jax.lax.broadcasted_iota(i32, (m, 1), 0)[:, 0]   # [m]
         iota_col = jax.lax.broadcasted_iota(i32, (m, m), 1)       # [m, m]
-        for j in range(JOB_BLOCK):
-            order = order_ref[j, :]
-            p_sorted = psort_ref[j, :]
-            parent = parent_ref[j, :]
-            visible = vis_ref[j, :] != 0
-            valid = valid_ref[j, :] != 0
+        def job(j, carry):
+            row = pl.ds(j, 1)
+            order = order_ref[row, :][0]
+            p_sorted = psort_ref[row, :][0]
+            parent = parent_ref[row, :][0]
+            visible = vis_ref[row, :][0] != 0
+            valid = valid_ref[row, :][0] != 0
 
             # ---- thread the tree from the sorted order ----------------
             # (1-D bool concats hit Mosaic vreg-cast limits: shift in i32)
@@ -148,9 +157,12 @@ def _make_kernel(m, rounds):
             vis_rank = run - vis_ordered                 # exclusive
             vis_index = dot(N, vis_rank[:, None])[:, 0].astype(i32)
             vis_index = jnp.where(visible & on_chain, vis_index, -1)
-            visidx_ref[j, :] = vis_index
-            len_ref[j, :] = jnp.broadcast_to(
-                jnp.sum((visible & on_chain).astype(i32)), (m,))
+            visidx_ref[row, :] = vis_index[None, :]
+            len_ref[row, :] = jnp.broadcast_to(
+                jnp.sum((visible & on_chain).astype(i32)), (1, m))
+            return carry
+
+        jax.lax.fori_loop(0, JOB_BLOCK, job, 0)
 
     return kernel
 
@@ -182,6 +194,9 @@ def rga_order_batch_pallas(parent, elem, actor, visible, valid,
     K, m = parent.shape
     K_pad = _round_up(max(K, 1), JOB_BLOCK)
     m_pad = _round_up(max(m, 2), NODE_TILE)
+    if m_pad > MAX_M and not interpret:
+        raise ValueError(f'rga_order_batch_pallas compiles for m <= '
+                         f'{MAX_M} on a TPU, got m={m}')
 
     def pad(a, fill):
         out = jnp.full((K_pad, m_pad), fill, jnp.int32)

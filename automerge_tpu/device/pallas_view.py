@@ -25,11 +25,14 @@ each other in CI:
   gather), the production path on CPU and for large planes;
 * :func:`edit_stream_pallas` — the hand-fused Pallas variant next to
   :mod:`.pallas_sequence`: each job's planes stay resident in VMEM and
-  every scatter/gather rides a one-hot MXU matmul. Like the RGA MXU
-  variant, the one-hot build is O(m^2) VPU compares, so the intended
-  regime is m <= ~512 (the measured one-hot crossover documented in
-  pallas_sequence's module docstring); the CPU CI lane runs it in
-  interpret mode.
+  every scatter/gather (and the prefix sum, as a triangular matrix)
+  rides an MXU matmul. Like the RGA MXU variant, the one-hot build is
+  O(m^2) VPU compares, so the auto-dispatch regime is m <= 512 (the
+  one-hot crossover documented in pallas_sequence's module docstring;
+  the v5e compile at m = 128 and m = 512 is pinned in
+  tests/test_tpu_compile.py). Each job is one grid step over a
+  ``(1, m)`` block of a ``[K, 1, m]`` array, so any K is legal. The
+  CPU CI lane runs it in interpret mode.
 
 The ``_FUSED_VIEW`` switch mirrors the native-path conventions:
 ``None`` = auto (Pallas on a real TPU backend inside the small-plane
@@ -50,7 +53,7 @@ _FUSED_VIEW = None
 # run the Pallas kernel in interpret mode (CPU CI lane)
 _INTERPRET = False
 # auto-dispatch bound: the one-hot MXU schedule is only profitable for
-# small planes (see pallas_sequence's measured crossover)
+# small planes (see pallas_sequence's one-hot crossover)
 _PALLAS_MAX_M = 512
 
 _W2_ELEM = 0x7FFF
@@ -142,7 +145,7 @@ def edit_stream_wide(vis_prior, vis_new, touched_u8, *, e_pad):
 
 # -- hand-fused Pallas variant ------------------------------------------------
 
-def _make_edit_kernel(m, e_pad, rounds):
+def _make_edit_kernel(m, e_pad):
     from jax.experimental import pallas as pl  # noqa: F401
     f32 = jnp.float32
     i32 = jnp.int32
@@ -157,6 +160,11 @@ def _make_edit_kernel(m, e_pad, rounds):
                rm_ref, insn_ref, insi_ref, setn_ref, seti_ref,
                cnt_ref):
         iota_m = jax.lax.broadcasted_iota(i32, (m, m), 1)      # [m, m]
+        # strictly-lower-triangular ones: `before @ x` is the exclusive
+        # prefix sum of x on the MXU (lane-shifted concatenates past the
+        # first 128-lane tile do not lower in Mosaic)
+        before = (iota_m < jax.lax.broadcasted_iota(i32, (m, m), 0)) \
+            .astype(f32)
         iota_e = jax.lax.broadcasted_iota(i32, (m, e_pad), 1)  # [m, e]
         pv = pv_ref[0, :] != 0
         nv = nv_ref[0, :] != 0
@@ -170,23 +178,16 @@ def _make_edit_kernel(m, e_pad, rounds):
 
         def compact(mask, key, vals, out_refs):
             # presence grid via one-hot matmul (keys unique per mask
-            # row, so the sum IS the presence bit), prefix sum via
-            # log-shifted adds, rank gather + e-space scatter as two
+            # row, so the sum IS the presence bit), prefix sum as a
+            # triangular matmul, rank gather + e-space scatter as two
             # more one-hot dots — the whole class pipeline stays on
             # the MXU/VPU, no sort anywhere
             key_c = jnp.minimum(jnp.maximum(key, 0), m - 1)
             G = (key_c[:, None] == iota_m).astype(f32) \
                 * mask.astype(f32)[:, None]                 # [l, p]
-            grid = dot(G.T, jnp.ones((m, 1), f32))[:, 0]    # [p]
-            run = grid
-            for k in range(rounds):                 # inclusive scan
-                s = 1 << k
-                if s >= m:
-                    break
-                run = run + jnp.concatenate(
-                    [jnp.zeros((s,), f32), run[:m - s]])
-            rank_g = run - grid                      # exclusive
-            rank = dot(G, rank_g[:, None])[:, 0].astype(i32)
+            grid = dot(G.T, jnp.ones((m, 1), f32))           # [p, 1]
+            rank_g = dot(before, grid)              # exclusive scan
+            rank = dot(G, rank_g)[:, 0].astype(i32)
             E = (rank[:, None] == iota_e).astype(f32) \
                 * mask.astype(f32)[:, None]                 # [l, e]
             present = dot(E.T, jnp.ones((m, 1), f32))[:, 0] > 0
@@ -217,29 +218,28 @@ def _edit_stream_pallas_core(pv, nv, pi, ni, touched, *, e_pad,
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
     from .pallas_merge import _round_up
-    from .sequence import _ceil_log2
     K, m = pv.shape
     m_pad = _round_up(max(m, 2), 128)
     e_out = _round_up(max(e_pad, 8), 128)
 
     def pad(a, fill):
-        out = jnp.full((K, m_pad), fill, jnp.int32)
-        return out.at[:, :m].set(a.astype(jnp.int32))
+        out = jnp.full((K, 1, m_pad), fill, jnp.int32)
+        return out.at[:, 0, :m].set(a.astype(jnp.int32))
 
-    spec_in = pl.BlockSpec((1, m_pad), lambda d: (d, 0),
+    spec_in = pl.BlockSpec((None, 1, m_pad), lambda d: (d, 0, 0),
                            memory_space=pltpu.VMEM)
-    spec_out = pl.BlockSpec((1, e_out), lambda d: (d, 0),
+    spec_out = pl.BlockSpec((None, 1, e_out), lambda d: (d, 0, 0),
                             memory_space=pltpu.VMEM)
     outs = pl.pallas_call(
-        _make_edit_kernel(m_pad, e_out, _ceil_log2(m_pad) + 1),
+        _make_edit_kernel(m_pad, e_out),
         grid=(K,),
         in_specs=[spec_in] * 5,
         out_specs=[spec_out] * 6,
-        out_shape=[jax.ShapeDtypeStruct((K, e_out), jnp.int32)] * 6,
+        out_shape=[jax.ShapeDtypeStruct((K, 1, e_out), jnp.int32)] * 6,
         interpret=interpret,
     )(pad(pv, 0), pad(nv, 0), pad(pi, -1), pad(ni, -1),
       pad(touched, 0))
-    rm, insn, insi, setn, seti, cnt = outs
+    rm, insn, insi, setn, seti, cnt = (o[:, 0] for o in outs)
     return (rm[:, :e_pad], insn[:, :e_pad], insi[:, :e_pad],
             setn[:, :e_pad], seti[:, :e_pad], cnt[:, :3])
 
@@ -275,11 +275,21 @@ def dispatch_edit_stream(vis_fmt, planes, touched_u8, e_pad):
     """Dispatch the edit-stream kernel over one apply's vis planes
     (device-resident outputs of the fused apply) — the entry point
     ``GeneralPatch._ensure`` calls. Returns the device output tuple
-    (fetch with one ``jax.device_get``)."""
+    (fetch with one ``jax.device_get``). Each dispatch lands in the
+    profiler's registry as ``view.edit_stream_pallas`` or
+    ``view.edit_stream`` (the lax programs), so a run can tell which
+    implementation served its patch reads."""
+    from . import profiler as _profiler
     t_u8 = jnp.asarray(touched_u8)
+    first = planes if vis_fmt == 'packed' else planes[0]
+    K, m = (int(n) for n in np.shape(first))
+    use_pallas = _use_pallas(m)
+    _profiler.note_dispatch(
+        'view.edit_stream_pallas' if use_pallas else 'view.edit_stream',
+        (vis_fmt, K, m, e_pad), rows=K)
     if vis_fmt == 'packed':
         v = planes
-        if _use_pallas(int(v.shape[1])):
+        if use_pallas:
             pv = ((v >> 31) & 1)
             nv = ((v >> _W2_VIS_SHIFT) & 1)
             pi = ((v >> _W2_IDX_SHIFT) & _W2_ELEM) - 1
@@ -290,7 +300,7 @@ def dispatch_edit_stream(vis_fmt, planes, touched_u8, e_pad):
         return edit_stream_packed(v, t_u8, e_pad=e_pad)
     if vis_fmt == 'wide':
         vp, vn = planes
-        if _use_pallas(int(vp.shape[1])):
+        if use_pallas:
             pv = (vp >> _WIDE_VIS_SHIFT) & 1
             nv = (vn >> _WIDE_VIS_SHIFT) & 1
             pi = (vp & _WIDE_IDX_MASK) - 1
@@ -300,7 +310,7 @@ def dispatch_edit_stream(vis_fmt, planes, touched_u8, e_pad):
                                       interpret=_INTERPRET)
         return edit_stream_wide(vp, vn, t_u8, e_pad=e_pad)
     pv, nv, pi, ni = planes
-    if _use_pallas(int(np.shape(pv)[1])):
+    if use_pallas:
         return edit_stream_pallas(pv, nv, pi, ni, t_u8, e_pad=e_pad,
                                   interpret=_INTERPRET)
     return edit_stream(pv, nv, pi, ni, t_u8, e_pad=e_pad)

@@ -135,6 +135,13 @@ def note_dispatch(fn, signature, rows=None, jit=True):
     return True
 
 
+def signatures(fn):
+    """The shape signatures tracked entry point ``fn`` has dispatched
+    with (a copy; empty when it never ran)."""
+    with _lock:
+        return set(_signatures.get(fn, ()))
+
+
 def signature_counts():
     """{fn: distinct signatures seen} — the live registry view."""
     with _lock:

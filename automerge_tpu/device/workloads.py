@@ -99,3 +99,39 @@ def gen_block_workload(n_docs=10240, n_actors=10, ops_per_change=10,
         [f'peer-{i:03d}' for i in range(n_actors)],
         [f'field{i:02d}' for i in range(n_keys)], values,
         dup_keys=False)          # keys are distinct per change by draw
+
+
+def gen_mixed_docs(n_docs, list_ops, doc0=0):
+    """The general engine's config-5 fleet as dict changes: per doc a
+    list object linked at ``items``, two actors with a causal chain
+    (``list_ops`` interleaved ins/set pairs, half per actor) and a
+    root ``meta`` set — ``2 * list_ops + 3`` ops per doc. Deterministic
+    (no randomness): doc ``d`` always carries the same changes."""
+    from ..common import ROOT_ID
+    per_doc = []
+    for d in range(doc0, doc0 + n_docs):
+        obj = f'00000000-0000-4000-8000-{d:012x}'
+        ops1 = [{'action': 'makeList', 'obj': obj},
+                {'action': 'link', 'obj': ROOT_ID, 'key': 'items',
+                 'value': obj}]
+        prev = '_head'
+        for i in range(list_ops // 2):
+            ops1.append({'action': 'ins', 'obj': obj, 'key': prev,
+                         'elem': i + 1})
+            prev = f'w0-{d}:{i + 1}'
+            ops1.append({'action': 'set', 'obj': obj, 'key': prev,
+                         'value': i})
+        ops2 = []
+        for i in range(list_ops // 2, list_ops):
+            ops2.append({'action': 'ins', 'obj': obj, 'key': prev,
+                         'elem': i + 1})
+            prev = f'w1-{d}:{i + 1}'
+            ops2.append({'action': 'set', 'obj': obj, 'key': prev,
+                         'value': i})
+        ops2.append({'action': 'set', 'obj': ROOT_ID, 'key': 'meta',
+                     'value': d})
+        per_doc.append([
+            {'actor': f'w0-{d}', 'seq': 1, 'deps': {}, 'ops': ops1},
+            {'actor': f'w1-{d}', 'seq': 1, 'deps': {f'w0-{d}': 1},
+             'ops': ops2}])
+    return per_doc

@@ -147,13 +147,15 @@ class GeneralDocSet:
     ``capacity`` documents at most (the store's document axis);
     document ids map to store doc indexes on first touch. The full op
     set is in scope — nested objects, lists, text, links, causal
-    buffering, conflicts.
+    buffering, conflicts. ``device`` pins the store's device planes to
+    one device (None = jax's default placement).
     """
 
-    def __init__(self, capacity, options=None, auto_grow=True):
+    def __init__(self, capacity, options=None, auto_grow=True,
+                 device=None):
         self.capacity = capacity
         self.auto_grow = auto_grow
-        self.store = _general.init_store(capacity)
+        self.store = _general.init_store(capacity, device=device)
         self._options = options
         self.ids = []                  # index -> doc_id
         self.id_of = {}                # doc_id -> index
@@ -804,7 +806,8 @@ class GeneralDocSet:
         old = self.store
         old._commit_pending()
         old.pool.sync()
-        new_store = _general.init_store(self.capacity)
+        new_store = _general.init_store(self.capacity,
+                                        device=old.device)
         resident = [i for i in range(len(self.ids)) if i not in drop]
         # compacted survivors restore tiered: their state-at-horizon
         # absorbs wholesale (no pre-horizon bodies exist to replay),
@@ -1211,7 +1214,7 @@ class GeneralDocSet:
         return struct.pack('>Q', len(header)) + header + store_bytes
 
     @classmethod
-    def load_snapshot(cls, data, options=None):
+    def load_snapshot(cls, data, options=None, device=None):
         import json
         import struct
         from ..snapshot import SnapshotCorruptError
@@ -1240,9 +1243,10 @@ class GeneralDocSet:
                     f"'{field}'")
         try:
             out = cls(header['capacity'], options=options,
-                      auto_grow=header.get('auto_grow', True))
+                      auto_grow=header.get('auto_grow', True),
+                      device=device)
             out.store = _general.GeneralStore.load_snapshot(
-                data[8 + hlen:])
+                data[8 + hlen:], device=device)
             out.ids = list(header['ids'])
             out.id_of = {doc_id: i
                          for i, doc_id in enumerate(out.ids)}

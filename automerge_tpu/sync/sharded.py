@@ -46,7 +46,6 @@ sustained imbalance; see ``control._placement_rule``.
 
 import base64
 import bisect
-import contextlib
 import hashlib
 import json
 import time as _time
@@ -57,11 +56,6 @@ import numpy as np
 from ..utils.metrics import metrics as _metrics
 from .general_doc_set import (DEFAULT_HEALTH_THRESHOLDS, GeneralDocSet,
                               _latency_quantiles)
-
-try:
-    import jax
-except Exception:                      # pragma: no cover - jaxless host
-    jax = None
 
 _MIGRATE_FORMAT = 'automerge-tpu-migration-unit@1'
 _SNAP_FORMAT = 'automerge-tpu-sharded-docset-snapshot@1'
@@ -185,12 +179,12 @@ class ShardedGeneralDocSet:
     1/N cut and auto-grows independently. ``mesh`` (a 1-D doc-axis
     mesh, default :func:`~automerge_tpu.parallel.mesh.make_mesh` over
     the visible devices) places shard *i*'s planes on device
-    ``i % mesh_size`` — every shard-routed call runs under that
-    device's ``jax.default_device`` so the store's arrays land where
-    the placement map says. ``shard_factory(index, capacity)`` swaps
-    the per-shard doc set class (a serving wrapper makes
+    ``i % mesh_size``: each shard's store creates its planes committed
+    to that device, so every program over them runs there with no
+    per-call device context. ``shard_factory(index, capacity, device)``
+    swaps the per-shard doc set class (a serving wrapper makes
     eviction/fault-in shard-local — each shard manages its own
-    residency budget).
+    residency budget); it places the shard's planes on ``device``.
 
     The surface mirrors :class:`GeneralDocSet`; handlers fire at THIS
     layer (per requested doc, after the shard-routed apply), so a
@@ -199,37 +193,24 @@ class ShardedGeneralDocSet:
 
     def __init__(self, capacity, n_shards=None, mesh=None, options=None,
                  auto_grow=True, shard_factory=None, replicas=32):
-        if mesh is None and jax is not None:
-            try:
-                from ..parallel.mesh import make_mesh
-                mesh = make_mesh()
-            except Exception:
-                mesh = None
+        from ..parallel.mesh import make_mesh, shard_device
+        if mesh is None:
+            mesh = make_mesh()
         self.mesh = mesh
         if n_shards is None:
-            n_shards = mesh.devices.size if mesh is not None else 1
+            n_shards = mesh.devices.size
         self.n_shards = max(1, int(n_shards))
         self.capacity = capacity
         self._options = options
         per_shard = max(4, -(-capacity // self.n_shards))
         if shard_factory is None:
-            def shard_factory(i, cap):
+            def shard_factory(i, cap, device):
                 return GeneralDocSet(cap, options=options,
-                                     auto_grow=auto_grow)
-        self.devices = [None] * self.n_shards
-        if mesh is not None:
-            from ..parallel.mesh import shard_device
-            self.devices = [shard_device(mesh, i)
-                            for i in range(self.n_shards)]
-        # build each shard UNDER its device context so the store's
-        # planes commit there; routine applies then skip the context
-        # (committed operands keep the placement, and jit dispatch
-        # under an explicit default_device loses its C++ fast path —
-        # ~0.15 ms instead of ~0.01 ms per call)
-        self.shards = []
-        for i in range(self.n_shards):
-            with self._on(i):
-                self.shards.append(shard_factory(i, per_shard))
+                                     auto_grow=auto_grow, device=device)
+        self.devices = [shard_device(mesh, i)
+                        for i in range(self.n_shards)]
+        self.shards = [shard_factory(i, per_shard, self.devices[i])
+                       for i in range(self.n_shards)]
         self.placement = PlacementMap(self.n_shards, replicas=replicas)
         self._doc_shard = {}           # doc_id -> owning shard (live)
         self._fences = {}              # doc_id -> buffered work items
@@ -274,12 +255,6 @@ class ShardedGeneralDocSet:
             s = self.placement.shard_of(doc_id)
             self._doc_shard[doc_id] = s
         return s
-
-    def _on(self, shard):
-        dev = self.devices[shard]
-        if dev is None or jax is None:
-            return contextlib.nullcontext()
-        return jax.default_device(dev)
 
     def _group(self, doc_ids, create=False):
         by_shard = {}
@@ -446,9 +421,8 @@ class ShardedGeneralDocSet:
     def apply_states(self, payload_by_doc):
         out = {}
         for s, ids in self._group(payload_by_doc, create=True).items():
-            with self._on(s):
-                out.update(self.shards[s].apply_states(
-                    {d: payload_by_doc[d] for d in ids}))
+            out.update(self.shards[s].apply_states(
+                {d: payload_by_doc[d] for d in ids}))
         self._fire(out)
         return out
 
@@ -611,17 +585,15 @@ class ShardedGeneralDocSet:
             try:
                 unit = encode_migration_unit(records[doc_id])
                 rec = decode_migration_unit(unit)
-                with self._on(dst):
-                    if 'state' in rec:
-                        dst_set.apply_states(
-                            {doc_id:
-                             base64.b64decode(rec['state'])})
-                    else:
-                        dst_set.apply_changes_batch(
-                            {doc_id: rec.get('changes', [])})
-                    if rec.get('queued'):
-                        dst_set.apply_changes_batch(
-                            {doc_id: rec['queued']})
+                if 'state' in rec:
+                    dst_set.apply_states(
+                        {doc_id: base64.b64decode(rec['state'])})
+                else:
+                    dst_set.apply_changes_batch(
+                        {doc_id: rec.get('changes', [])})
+                if rec.get('queued'):
+                    dst_set.apply_changes_batch(
+                        {doc_id: rec['queued']})
                 if verify:
                     want = src_set.digest_of_id(doc_id)
                     got = dst_set.digest_of_id(doc_id)
@@ -686,9 +658,8 @@ class ShardedGeneralDocSet:
                     dup_keys=None, obj=item.obj,
                     key_kind=item.key_kind, key_elem=item.key_elem,
                     elem=item.elem, objs=item.objs)
-                with self._on(s):
-                    _general.apply_general_block(
-                        shard.store, widened, options=shard._options)
+                _general.apply_general_block(
+                    shard.store, widened, options=shard._options)
                 self._note_load(s, doc_id, max(len(item.doc), 1))
 
     # -- load telemetry / maintenance ---------------------------------------
@@ -805,15 +776,13 @@ class ShardedGeneralDocSet:
             'health': self.evaluate_health(),
             'placement': {
                 'n_shards': self.n_shards,
-                'mesh_devices': self.mesh.devices.size
-                if self.mesh is not None else 0,
+                'mesh_devices': self.mesh.devices.size,
                 'overrides': len(self.placement.pins),
                 'imbalance': round(self._imbalance, 4),
                 'migrations': int(self._migrations_in.sum()),
                 'per_shard': [
                     {'shard': s,
-                     'device': str(self.devices[s])
-                     if self.devices[s] is not None else None,
+                     'device': str(self.devices[s]),
                      'docs': int(docs_per[s]),
                      'resident_bytes': int(stats[s, 3]),
                      'apply_ops': int(self._last_window[s]),
@@ -869,8 +838,8 @@ class ShardedGeneralDocSet:
         out._doc_shard = {d: int(s)
                           for d, s in snap['doc_shard'].items()}
         out.shards = [GeneralDocSet.load_snapshot(
-            base64.b64decode(s), options=options)
-            for s in snap['shards']]
+            base64.b64decode(s), options=options, device=dev)
+            for s, dev in zip(snap['shards'], out.devices)]
         return out
 
     loadSnapshot = load_snapshot

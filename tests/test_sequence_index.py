@@ -539,13 +539,18 @@ class TestEditStream:
         tb = np.packbits(touched, axis=1)
         return pv, nv, pi, ni, tb
 
-    def test_pallas_interpret_parity(self):
+    @pytest.mark.parametrize('K,m', [
+        (5, 64),      # job count not a multiple of 8
+        (8, 128),     # one lane tile
+        (3, 300),     # multi-tile node axis, ragged tail
+    ])
+    def test_pallas_interpret_parity(self, K, m):
         """The hand-fused Pallas winner/visible/order kernel is
         bit-identical to the lax fallback — interpret mode on CPU (the
-        TPU compile path is covered by the same call on real chips)."""
-        rng = np.random.default_rng(42)
+        v5e compile is pinned in tests/test_tpu_compile.py)."""
+        rng = np.random.default_rng(42 + K)
         for e_pad in (8, 24):
-            pv, nv, pi, ni, tb = self._random_planes(rng)
+            pv, nv, pi, ni, tb = self._random_planes(rng, K=K, m=m)
             lax_out = jax.device_get(pallas_view.edit_stream(
                 pv, nv, pi, ni, tb, e_pad=e_pad))
             pl_out = jax.device_get(pallas_view.edit_stream_pallas(

@@ -152,6 +152,36 @@ class TestMeshPlacement:
         assert sharded.n_shards == 8
         assert len({str(d) for d in sharded.devices}) == 8
 
+    @staticmethod
+    def _assert_planes_home(sharded):
+        for i, shard in enumerate(sharded.shards):
+            planes = [v for v in shard.store.pool.mirror.values()
+                      if isinstance(v, jax.Array)]
+            assert planes, f'shard {i} holds no device planes'
+            for v in planes:
+                assert v.committed
+                assert v.devices() == {sharded.devices[i]}
+
+    @pytest.mark.parametrize('resume', [False, True],
+                             ids=['applied', 'snapshot_resumed'])
+    def test_shard_planes_committed_to_own_device(self, resume):
+        """No device context on routine calls: each shard's store
+        commits its mirror to its device, and every later apply,
+        migration or resume keeps it there."""
+        sharded, _ = seeded_pair(n_docs=24)
+        assert all(len(s.doc_ids) for s in sharded.shards)
+        doc = 'doc0'
+        assert sharded.migrate_doc(doc, (sharded.shard_of(doc) + 1) % 4)
+        if resume:
+            sharded = ShardedGeneralDocSet.load_snapshot(
+                sharded.save_snapshot(), mesh=_mesh(4))
+        sharded.apply_changes(doc, [
+            {'actor': 'late', 'seq': 1,
+             'deps': sharded.clock_of_id(doc),
+             'ops': [{'action': 'set', 'obj': ROOT_ID, 'key': 'late',
+                      'value': 1}]}])
+        self._assert_planes_home(sharded)
+
 
 class TestMigration:
     def test_parity_after_migration(self):
