@@ -413,6 +413,8 @@ class ChaosFleet:
 
 import asyncio  # noqa: E402
 
+from .transport import CLOSE_GRACE_S, stream_closed  # noqa: E402
+
 
 class ChaosProxy:
     """A fault-injecting TCP proxy for ONE peer pair: the dialing
@@ -467,6 +469,8 @@ class ChaosProxy:
             return
         pipe = (cwriter, swriter)
         self.pipes.add(pipe)
+        if self.partitioned:           # severed while dialing
+            self._kill_pipes()
         pumps = (asyncio.ensure_future(self._pump(creader, swriter)),
                  asyncio.ensure_future(self._pump(sreader, cwriter)))
         try:
@@ -476,12 +480,14 @@ class ChaosProxy:
             for task in pumps:
                 if not task.done():
                     task.cancel()
-            self.pipes.discard(pipe)
+            # the pipe stays listed until both sockets have closed:
+            # one still flushing to a peer that stopped reading is
+            # aborted by sever()/close()
             for writer in pipe:
-                try:
-                    writer.close()
-                except Exception:
-                    pass
+                writer.close()
+            for writer in pipe:
+                await stream_closed(writer)
+            self.pipes.discard(pipe)
 
     async def _pump(self, reader, writer):
         try:
@@ -530,16 +536,19 @@ class ChaosProxy:
 
     async def sever(self):
         """Dead cable: stop listening (new dials are refused — the
-        endpoints' re-dial backoff takes over) and abort live pipes."""
+        endpoints' re-dial backoff takes over) and abort live pipes.
+        The pipes go first: the server's wait covers every connection
+        it accepted, so waiting before the abort waits for ever."""
         self.partitioned = True
+        self._kill_pipes()
         if self._server is not None:
             self._server.close()
             try:
-                await self._server.wait_closed()
+                await asyncio.wait_for(self._server.wait_closed(),
+                                       CLOSE_GRACE_S)
             except Exception:
                 pass
             self._server = None
-        self._kill_pipes()
 
     async def heal(self):
         self.partitioned = False
@@ -547,15 +556,8 @@ class ChaosProxy:
             await self.start()         # same recorded port
 
     async def close(self):
-        self.partitioned = True
-        if self._server is not None:
-            self._server.close()
-            try:
-                await self._server.wait_closed()
-            except Exception:
-                pass
-            self._server = None
-        self._kill_pipes()
+        """Shut the proxy down: a sever that is never healed."""
+        await self.sever()
 
 
 class SocketChaosFleet:

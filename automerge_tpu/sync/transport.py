@@ -113,6 +113,36 @@ COMPACT_AT = 64 * 1024
 # wire-session resume path)
 _EPOCH_COUNTER = itertools.count(1)
 
+# shutdown bound: how long a graceful close may take to flush before
+# the socket is aborted (a peer that stopped reading would hold the
+# flush, and the close, open forever), and the backstop on a server's
+# wait for its accepted connections
+CLOSE_GRACE_S = 1.0
+
+
+async def stream_closed(writer):
+    """Wait until a stream's transport has closed; a reset counts."""
+    try:
+        await writer.wait_closed()
+    except Exception:
+        pass
+
+
+async def _close_streams(writers):
+    """Close streams gracefully, then abort every one whose transport
+    is still open after :data:`CLOSE_GRACE_S`."""
+    waits = {}
+    for writer in writers:
+        writer.close()
+        waits[asyncio.ensure_future(stream_closed(writer))] = writer
+    if not waits:
+        return
+    _done, stuck = await asyncio.wait(waits, timeout=CLOSE_GRACE_S)
+    for task in stuck:
+        waits[task].transport.abort()
+    if stuck:
+        await asyncio.wait(stuck, timeout=CLOSE_GRACE_S)
+
 
 class FrameError(ValueError):
     """A frame failed to decode (bad magic, out-of-bounds length,
@@ -158,7 +188,12 @@ def encode_frame_iov(dset, env):
                 if not isinstance(part, bytes):
                     part = bytes(part)
                 binfields.append([f, len(part)])
-                body_parts.append(part)
+                # an empty field (a zero-change advert's blob) stays
+                # out of the iovec: CPython 3.12's socket transport
+                # never pops a trailing zero-length part, so its write
+                # buffer never empties and close() waits forever
+                if part:
+                    body_parts.append(part)
             env = {**env, 'payload': head_payload}
     head = {'d': dset, 'e': env}
     if binfields:
@@ -405,6 +440,7 @@ class TransportEndpoint:
         self.now = 0
         self.closed = False
         self._server = None
+        self._accepted = set()         # accepted streams not yet closed
         self.metrics = metrics.scoped(node=str(node_id))
 
     # -- lifecycle -----------------------------------------------------------
@@ -430,27 +466,31 @@ class TransportEndpoint:
 
     async def close(self):
         """Graceful shutdown: stop the server, close every socket and
-        connection (links unregister from their doc sets)."""
+        connection (links unregister from their doc sets). Every
+        socket is closed here, the accepted ones still before their
+        HELLO too, before anything waits on the server; one that
+        cannot flush in :data:`CLOSE_GRACE_S` is aborted, so shutdown
+        finishes even when a peer stopped reading."""
         self.closed = True
         if self._server is not None:
             self._server.close()
+        writers = set(self._accepted)
         for link in self.peers.values():
             self._cancel_tasks(link)
             self._drop_kicker(link)
             if link.writer is not None:
-                try:
-                    link.writer.close()
-                except Exception:
-                    pass
+                writers.add(link.writer)
                 link.writer = None
             for conn in link.conns.values():
                 try:
                     conn.close()
                 except Exception:
                     pass
+        await _close_streams(writers)
         if self._server is not None:
             try:
-                await self._server.wait_closed()
+                await asyncio.wait_for(self._server.wait_closed(),
+                                       CLOSE_GRACE_S)
             except Exception:
                 pass
         await asyncio.sleep(0)
@@ -522,8 +562,16 @@ class TransportEndpoint:
 
     async def _accept(self, reader, writer):
         """Server side: the peer identifies itself with the first
-        (HELLO) frame; until then nothing is routable."""
-        await self._read_loop(None, reader, writer)
+        (HELLO) frame; until then nothing is routable. The stream
+        stays in ``_accepted`` until its transport has closed, so
+        :meth:`close` can close (or abort) it before waiting on the
+        server."""
+        self._accepted.add(writer)
+        try:
+            await self._read_loop(None, reader, writer)
+            await stream_closed(writer)
+        finally:
+            self._accepted.discard(writer)
 
     def _on_hello(self, ctl, writer):
         peer = ctl.get('node')
@@ -723,6 +771,15 @@ class TransportEndpoint:
                     link.wake.clear()
                     await link.wake.wait()
                     continue
+                if writer.is_closing():
+                    # the socket is gone (a reset the read loop has
+                    # not seen yet). CPython 3.12's writelines() would
+                    # register the closed fd with the selector again,
+                    # stealing the registration of the next socket
+                    # that reuses the number: a re-dial never connects.
+                    # The frames stay queued for that socket.
+                    self._detach_socket(link, writer)
+                    return
                 # drain the WHOLE queue into one writelines/drain
                 # cycle: no per-frame write() calls, no join — the
                 # iovec parts go straight to the transport. There is

@@ -396,7 +396,6 @@ class TestPlacementKnob:
             {f'doc{d}': rich_changes(d) for d in range(n_docs)})
         return sharded
 
-    @pytest.mark.slow
     def test_drains_hot_shard(self):
         sharded = self._loaded_fleet(n_docs=10)
         FleetController(sharded, hold=2, cooldown=2,
@@ -509,7 +508,6 @@ def _chaos_run(seed, migrate_every=3):
 
 
 class TestChaosWithMigration:
-    @pytest.mark.slow
     def test_converges_byte_identical_to_oracle(self):
         sharded, oracle = _chaos_run(seed=11)
         assert not sharded.quarantined
@@ -519,7 +517,6 @@ class TestChaosWithMigration:
             assert int(sharded.digest_of_id(d)) == \
                 int(oracle.digest_of_id(d))
 
-    @pytest.mark.slow
     @pytest.mark.skipif(not native.stage_available(),
                         reason='native stager unavailable')
     def test_forced_native_lane_matches(self):

@@ -11,8 +11,6 @@ it), but CRDT convergence makes the final state order-independent,
 which is precisely the property under test.
 """
 
-import pytest
-
 from automerge_tpu.fleetsim import build_schedule, run_oracle
 from automerge_tpu.sync.chaos import (ChaosProxy,
                                       replay_schedule_over_sockets)
@@ -36,7 +34,6 @@ class TestScheduleReplayOverSockets:
     def test_flash_crowd_matches_oracle(self):
         _assert_matches_oracle('flash_crowd', seed=5)
 
-    @pytest.mark.slow
     def test_reconnect_storm_matches_oracle(self):
         """Partitions + heals from the schedule map to severing and
         restarting the loopback proxies: re-dials see ECONNREFUSED,
@@ -44,7 +41,6 @@ class TestScheduleReplayOverSockets:
         path."""
         _assert_matches_oracle('reconnect_storm', seed=5)
 
-    @pytest.mark.slow
     def test_flash_crowd_heavy_faults(self):
         """Crank the fault knobs well past the default lane: the
         stream resets and re-dials must still land byte-identical."""

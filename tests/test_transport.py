@@ -11,6 +11,7 @@ incident, and a session resume that serves only the divergence
 window.
 """
 
+import asyncio
 import json
 import random
 import zlib
@@ -22,12 +23,14 @@ from automerge_tpu.durability import load_incident
 from automerge_tpu.sync import (FrameDecoder, FrameError,
                                 GeneralDocSet, ResilientConnection,
                                 ServingDocSet, WireConnection)
-from automerge_tpu.sync.chaos import (SocketChaosFleet, canonical,
-                                      doc_set_view)
+from automerge_tpu.sync.chaos import (ChaosProxy, SocketChaosFleet,
+                                      canonical, doc_set_view)
 from automerge_tpu.sync.transport import (CHANNELS, FRAME_MAGIC,
                                           MAX_FRAME_BYTES, _HEADER,
+                                          TransportEndpoint, _PeerLink,
                                           encode_ctl_frame,
-                                          encode_frame)
+                                          encode_frame,
+                                          encode_frame_iov)
 from automerge_tpu.utils.metrics import FlightRecorder, metrics
 
 
@@ -75,6 +78,18 @@ class TestFrameCodec:
         assert env['payload']['tab'] == b''
         assert env['payload']['n'] == 3
         assert env['payload']['name'] == 'café'
+
+    def test_empty_binary_field_leaves_no_empty_part(self):
+        """A zero-change advert's empty blob is no part of the iovec:
+        a trailing zero-length part never leaves the socket
+        transport's write buffer, and its close() then never ends."""
+        payload = {'docs': ['d0'], 'blob': b''}
+        _channel, parts, nbytes = encode_frame_iov(
+            'fleet', env_data(payload=payload))
+        assert all(len(p) for p in parts)
+        assert nbytes == sum(len(p) for p in parts)
+        [(_kind, _dset, env)] = FrameDecoder().feed(b''.join(parts))
+        assert env['payload']['blob'] == b''
 
     def test_ctl_roundtrip(self):
         frame = encode_ctl_frame({'hello': 1, 'node': 'n0',
@@ -628,8 +643,6 @@ class TestLivenessFastPath:
         """Keepalive pings and busy replies insert ahead of every
         queued data frame but BEHIND leading ctl frames, so a pending
         HELLO stays first on its socket."""
-        from automerge_tpu.sync.transport import (TransportEndpoint,
-                                                  _PeerLink)
         ep = TransportEndpoint('n0', {})
         link = _PeerLink('p0')
         hello = encode_ctl_frame({'hello': 1, 'node': 'n0'})
@@ -724,8 +737,6 @@ class TestTransportEndpoint:
         only one connect happens per direction."""
         a0, a1 = GeneralDocSet(8), GeneralDocSet(8)
         b0, b1 = GeneralDocSet(8), GeneralDocSet(8)
-        import asyncio
-        from automerge_tpu.sync.transport import TransportEndpoint
         loop = asyncio.new_event_loop()
         try:
             ea = TransportEndpoint('a', {'s0': a0, 's1': a1})
@@ -857,3 +868,124 @@ class TestTransportEndpoint:
             assert not serving.quarantined and not other.quarantined
         finally:
             fleet.close()
+
+
+# ---------------------------------------------------------------------------
+# shutdown: every close returns, whatever the peer is doing
+
+
+async def closes_in_time(coro, what):
+    try:
+        await asyncio.wait_for(coro, 5)
+    except asyncio.TimeoutError:
+        pytest.fail(f'{what} did not return within 5 s')
+
+
+class TestShutdown:
+    @pytest.mark.parametrize('order', ['dialer_first', 'acceptor_first',
+                                       'acceptor_after_peer_froze'])
+    def test_endpoint_close_returns(self, order):
+        """The dialer-first order is the mux test's. In the last case
+        the dialer stops reading (a frozen process): megabytes stay
+        queued on the acceptor's socket, and its close must give up
+        the flush instead of waiting for it."""
+        a, b = GeneralDocSet(8), GeneralDocSet(8)
+        loop = asyncio.new_event_loop()
+        ea = TransportEndpoint('a', {'s': a})
+        eb = TransportEndpoint('b', {'s': b})
+
+        async def go():
+            await ea.start()
+            await eb.start()
+            await ea.connect('b', '127.0.0.1', eb.port)
+            write(a, 'x', 'w0', 1)
+            write(b, 'y', 'w1', 2)
+            for _ in range(120):
+                await ea.tick()
+                await eb.tick()
+                for _ in range(6):
+                    await asyncio.sleep(0)
+                if not (ea.pending() or eb.pending()):
+                    break
+            first, second = (ea, eb) if order == 'dialer_first' \
+                else (eb, ea)
+            if order == 'acceptor_after_peer_froze':
+                ea.peers['b'].writer.transport.pause_reading()
+                stuck = eb.peers['a'].writer
+                stuck.write(b'\0' * (32 << 20))
+                await asyncio.sleep(0.05)
+                assert stuck.transport.get_write_buffer_size() > 0
+            await closes_in_time(first.close(), f'{first.node_id}.close()')
+            await closes_in_time(second.close(),
+                                 f'{second.node_id}.close()')
+        try:
+            loop.run_until_complete(go())
+        finally:
+            loop.close()
+        assert canonical(doc_set_view(a)) == canonical(doc_set_view(b))
+
+    def test_write_loop_leaves_a_closing_socket_alone(self):
+        """A socket reset that the read loop has not seen yet: the
+        write loop detaches it without a writelines() (which would
+        register the dead fd with the selector again) and keeps the
+        frames queued for the next socket."""
+        class Closing:
+            def is_closing(self):
+                return True
+
+            def writelines(self, parts):
+                raise AssertionError('writelines on a closing socket')
+
+            def close(self):
+                pass
+
+        ep = TransportEndpoint('n0', {})
+        link = _PeerLink('p0')
+        link.writer = writer = Closing()
+        frame = encode_frame('f', env_data())
+        link.outq.append((CHANNELS['data'], [frame], len(frame)))
+        asyncio.run(ep._write_loop(link, writer))
+        assert link.writer is None
+        assert len(link.outq) == 1
+
+    def test_proxy_sever_and_close_with_live_pipe(self):
+        """A live pipe through the proxy: sever() and close() both
+        return, and the pipe is cut."""
+        async def echo(reader, writer):
+            try:
+                while data := await reader.read(4096):
+                    writer.write(data)
+                    await writer.drain()
+            except ConnectionError:
+                pass
+            writer.close()
+
+        async def go():
+            target = await asyncio.start_server(echo, '127.0.0.1', 0)
+            port = target.sockets[0].getsockname()[1]
+            proxy = await ChaosProxy(lambda: port).start()
+            for step in ('sever', 'close'):
+                reader, writer = await asyncio.open_connection(
+                    '127.0.0.1', proxy.port)
+                writer.write(b'ping')
+                assert await asyncio.wait_for(
+                    reader.readexactly(4), 5) == b'ping'
+                await closes_in_time(getattr(proxy, step)(),
+                                     f'proxy.{step}()')
+                try:
+                    tail = await asyncio.wait_for(reader.read(), 5)
+                except ConnectionError:
+                    tail = b''
+                assert tail == b''
+                writer.close()
+                await proxy.heal()
+            await proxy.close()
+            target.close()
+            await closes_in_time(target.wait_closed(),
+                                 'target.wait_closed()')
+
+        loop = asyncio.new_event_loop()
+        try:
+            loop.run_until_complete(go())
+        finally:
+            loop.close()
