@@ -1142,6 +1142,12 @@ class GeneralStore(BlockStore):
         ``n_docs`` widens the block's document space beyond
         ``len(changes_per_doc)`` (a sparse tick touching few documents
         of a large store need not materialize one list per document).
+
+        A change whose ops are all set, del or link on maps (string
+        keys) is encoded a column at a time; any other change (object
+        creations, sequence keys) op by op. Both give the same block;
+        the counters ``encode_columnar_changes`` and
+        ``encode_per_op_changes`` count the changes each path took.
         """
         actors, actor_of = [], {}
         keys, key_of = [], {}
@@ -1149,25 +1155,38 @@ class GeneralStore(BlockStore):
         values = []
         doc, actor, seq = [], [], []
         dep_ptr, dep_actor, dep_seq = [0], [], []
-        op_ptr, action, key, value = [0], [], [], []
-        obj_col, kind_col, key_elem, elem_col = [], [], [], []
+        op_ptr, action, key = [0], bytearray(), []
+        # the op column ``obj`` as runs of one object
+        obj_run, obj_len = [], []
+        # the changes encoded op by op (by index) and their ops' key
+        # kind, elemId counter and ins counter; every other op has a
+        # string key and zeros there
+        po_changes, po_kind, po_key_elem, po_elem = [], [], [], []
+        created = None
 
-        # pass 1: objects created anywhere in the batch
-        created = dict(extra_types) if extra_types else {}
-        for d, changes in enumerate(changes_per_doc):
-            for change in changes:
-                for op in change['ops']:
-                    a = op['action']
-                    if a in ('makeMap', 'makeList', 'makeText'):
-                        created[(d, op['obj'])] = _MAKE_TYPE[
-                            _GEN_ACTION_NAMES[a]]
+        def batch_creations():
+            """Objects created anywhere in the batch (or in
+            ``extra_types``): a key kind can depend on a creation later
+            in the batch than the op."""
+            found = dict(extra_types) if extra_types else {}
+            for d, changes in enumerate(changes_per_doc):
+                for change in changes:
+                    for op in change['ops']:
+                        a = op['action']
+                        if a in ('makeMap', 'makeList', 'makeText'):
+                            found[(d, op['obj'])] = _MAKE_TYPE[
+                                _GEN_ACTION_NAMES[a]]
+            return found
 
         def obj_type_of(d, uuid):
+            nonlocal created
             if uuid == ROOT_ID:
                 return _TYPE_MAP
             row = self.obj_of.get((d, uuid))
             if row is not None:
                 return self.obj_type[row]
+            if created is None:
+                created = batch_creations()
             return created.get((d, uuid))       # None = unknown
 
         def check_seq_i32(v, what):
@@ -1177,85 +1196,174 @@ class GeneralStore(BlockStore):
                     f'{what} {v!r} out of range (must fit int32)')
             return v
 
+        def encode_per_op(d, ops):
+            """One change op by op: creations, sequence keys, and any
+            change the columnar path cannot take. True if the change
+            assigns one field twice."""
+            po_changes.append(len(doc) - 1)
+            dup = False
+            change_fields = set()
+            for op in ops:
+                a = op['action']
+                code = _GEN_ACTION_NAMES.get(a)
+                if code is None:
+                    raise ValueError(f'Unknown operation type {a}')
+                uuid = op['obj']
+                action.append(code)
+                obj_run.append(_intern(objs, obj_idx, uuid))
+                obj_len.append(1)
+                if code in (_MAKE_MAP, _MAKE_LIST, _MAKE_TEXT):
+                    po_kind.append(_KEY_NONE)
+                    key.append(-1)
+                    po_key_elem.append(0)
+                    po_elem.append(0)
+                    continue
+                k = op['key']
+                otype = obj_type_of(d, uuid)
+                as_elem = (otype in (_TYPE_LIST, _TYPE_TEXT))
+                if as_elem and k == '_head':
+                    if code != _INS:
+                        raise ValueError('assignment to _head')
+                    po_kind.append(_KEY_HEAD)
+                    key.append(-1)
+                    po_key_elem.append(0)
+                elif as_elem:
+                    ka, _, ke = k.rpartition(':')
+                    try:
+                        ke = int(ke)
+                    except ValueError:
+                        raise ValueError(
+                            f'malformed element id {k!r}') from None
+                    po_kind.append(_KEY_ELEM)
+                    key.append(_intern(actors, actor_of, ka))
+                    po_key_elem.append(ke)
+                else:
+                    po_kind.append(_KEY_STR)
+                    key.append(_intern(keys, key_of, k))
+                    po_key_elem.append(0)
+                if code == _INS:
+                    po_elem.append(op['elem'])
+                else:
+                    po_elem.append(0)
+                    if code in (_SET, _LINK):
+                        values.append(op.get('value'))
+                    fk = (uuid, k)
+                    if fk in change_fields:
+                        dup = True
+                    change_fields.add(fk)
+            return dup
+
         dup_keys = False
-        for d, changes in enumerate(changes_per_doc):
-            for change in changes:
-                if 'deps' not in change:
-                    raise ValueError('change requires actor, seq and deps')
-                doc.append(d)
-                actor.append(_intern(actors, actor_of, change['actor']))
-                seq.append(check_seq_i32(change['seq'], 'change seq'))
-                for da, ds in change['deps'].items():
-                    dep_actor.append(_intern(actors, actor_of, da))
-                    dep_seq.append(check_seq_i32(ds, 'dep seq'))
-                dep_ptr.append(len(dep_actor))
-                change_fields = set()
-                for op in change['ops']:
-                    a = op['action']
-                    code = _GEN_ACTION_NAMES.get(a)
-                    if code is None:
-                        raise ValueError(f'Unknown operation type {a}')
-                    uuid = op['obj']
-                    action.append(code)
-                    obj_col.append(_intern(objs, obj_idx, uuid))
-                    if code in (_MAKE_MAP, _MAKE_LIST, _MAKE_TEXT):
-                        kind_col.append(_KEY_NONE)
-                        key.append(-1)
-                        key_elem.append(0)
-                        elem_col.append(0)
-                        value.append(-1)
+        n_columnar = 0
+        # the key list of the last columnar change, its key ids and its
+        # number of distinct keys: changes of one schema repeat them
+        last_ks, last_ids, last_n_keys = None, None, 0
+        try:
+            for d, changes in enumerate(changes_per_doc):
+                for change in changes:
+                    if 'deps' not in change:
+                        raise ValueError(
+                            'change requires actor, seq and deps')
+                    doc.append(d)
+                    actor.append(_intern(actors, actor_of, change['actor']))
+                    seq.append(check_seq_i32(change['seq'], 'change seq'))
+                    for da, ds in change['deps'].items():
+                        dep_actor.append(_intern(actors, actor_of, da))
+                        dep_seq.append(check_seq_i32(ds, 'dep seq'))
+                    dep_ptr.append(len(dep_actor))
+                    ops = change['ops']
+                    # the columnar path: every op a set, del or link on a
+                    # map (or a not yet known object) with a string key.
+                    # A read that raises sends the change op by op, which
+                    # raises where it reaches the fault.
+                    try:
+                        acts = [op['action'] for op in ops]
+                        n = len(acts)
+                        n_set = acts.count('set')
+                        columnar = n_set == n or n_set + acts.count(
+                            'del') + acts.count('link') == n
+                        if columnar:
+                            uuids = [op['obj'] for op in ops]
+                            targets = (uuids[0],) if n and \
+                                uuids.count(uuids[0]) == n else set(uuids)
+                            for u in targets:
+                                if obj_type_of(d, u) in (_TYPE_LIST,
+                                                         _TYPE_TEXT):
+                                    columnar = False
+                                    break
+                        if columnar:
+                            ks = [op['key'] for op in ops]
+                            fresh = ks != last_ks
+                            if fresh:
+                                n_keys = len(set(ks))
+                    except (KeyError, TypeError):
+                        columnar = False
+                    if not columnar:
+                        dup_keys = encode_per_op(d, ops) or dup_keys
+                        op_ptr.append(len(key))
                         continue
-                    k = op['key']
-                    otype = obj_type_of(d, uuid)
-                    as_elem = (otype in (_TYPE_LIST, _TYPE_TEXT))
-                    if as_elem and k == '_head':
-                        if code != _INS:
-                            raise ValueError('assignment to _head')
-                        kind_col.append(_KEY_HEAD)
-                        key.append(-1)
-                        key_elem.append(0)
-                    elif as_elem:
-                        ka, _, ke = k.rpartition(':')
-                        try:
-                            ke = int(ke)
-                        except ValueError:
-                            raise ValueError(
-                                f'malformed element id {k!r}') from None
-                        kind_col.append(_KEY_ELEM)
-                        key.append(_intern(actors, actor_of, ka))
-                        key_elem.append(ke)
+                    n_columnar += 1
+                    if fresh:
+                        ids = list(map(key_of.get, ks))
+                        if None in ids:
+                            ids = [_intern(keys, key_of, k) for k in ks]
+                        last_ks, last_ids, last_n_keys = ks, ids, n_keys
+                    key.extend(last_ids)
+                    if len(targets) == 1:
+                        obj_run.append(_intern(objs, obj_idx, uuids[0]))
+                        obj_len.append(n)
+                        dup_keys = dup_keys or last_n_keys != n
                     else:
-                        kind_col.append(_KEY_STR)
-                        key.append(_intern(keys, key_of, k))
-                        key_elem.append(0)
-                    if code == _INS:
-                        elem_col.append(op['elem'])
-                        value.append(-1)
+                        obj_run.extend(
+                            [_intern(objs, obj_idx, u) for u in uuids])
+                        obj_len.extend([1] * n)
+                        dup_keys = dup_keys or \
+                            len(set(zip(uuids, ks))) != n
+                    if n_set == n:
+                        action.extend([_SET] * n)
+                        values.extend([op.get('value') for op in ops])
                     else:
-                        elem_col.append(0)
-                        if code in (_SET, _LINK):
-                            value.append(len(values))
-                            values.append(op.get('value'))
-                        else:
-                            value.append(-1)
-                        fk = (uuid, k)
-                        if fk in change_fields:
-                            dup_keys = True
-                        change_fields.add(fk)
-                op_ptr.append(len(action))
+                        action.extend([_GEN_ACTION_NAMES[a] for a in acts])
+                        values.extend([op.get('value') for op, a
+                                       in zip(ops, acts) if a != 'del'])
+                    op_ptr.append(len(key))
+        except Exception:
+            # a batch the creation scan cannot read fails with the
+            # scan's error, whichever change the encode stopped at
+            if created is None:
+                batch_creations()
+            raise
+        metrics.bump('encode_columnar_changes', n_columnar)
+        metrics.bump('encode_per_op_changes', len(po_changes))
+
+        n_ops = len(key)
+        action = np.frombuffer(action, np.int8)
+        # a set or link op points at the next row of ``values``
+        value = np.full(n_ops, -1, np.int32)
+        value[(action == _SET) | (action == _LINK)] = np.arange(
+            len(values), dtype=np.int32)
+        key_kind = np.full(n_ops, _KEY_STR, np.int8)
+        key_elem = np.zeros(n_ops, np.int32)
+        elem = np.zeros(n_ops, np.int32)
+        op_ptr = np.asarray(op_ptr, np.int32)
+        if po_changes:
+            c = np.asarray(po_changes, np.int64)
+            starts, counts = op_ptr[c], op_ptr[c + 1] - op_ptr[c]
+            at = np.repeat(starts - (np.cumsum(counts) - counts), counts) \
+                + np.arange(int(counts.sum()))
+            key_kind[at] = np.asarray(po_kind, np.int8)
+            key_elem[at] = np.asarray(po_key_elem, np.int32)
+            elem[at] = np.asarray(po_elem, np.int32)
 
         return ChangeBlock(
             n_docs if n_docs is not None else len(changes_per_doc),
             np.asarray(doc, np.int32), np.asarray(actor, np.int32),
             np.asarray(seq, np.int32), np.asarray(dep_ptr, np.int32),
             np.asarray(dep_actor, np.int32), np.asarray(dep_seq, np.int32),
-            np.asarray(op_ptr, np.int32), np.asarray(action, np.int8),
-            np.asarray(key, np.int32), np.asarray(value, np.int32),
+            op_ptr, action, np.asarray(key, np.int32), value,
             actors, keys, values, dup_keys=dup_keys,
-            obj=np.asarray(obj_col, np.int32),
-            key_kind=np.asarray(kind_col, np.int8),
-            key_elem=np.asarray(key_elem, np.int32),
-            elem=np.asarray(elem_col, np.int32), objs=objs)
+            obj=np.repeat(np.asarray(obj_run, np.int32), obj_len),
+            key_kind=key_kind, key_elem=key_elem, elem=elem, objs=objs)
 
     def merge_queued_into(self, block):
         """Re-encode the buffered queue (kinds resolve against the
