@@ -13,7 +13,16 @@ metric is found by name under this directory:
 * ``kinds/<kind>.py`` builds a request kind's data from the seed, warms
   up, serves one iteration or request, and checks the answers;
 * ``layers/<metric>.py`` reads one per-layer metric; its ``read(ctx)``
-  returns a number, or None where the run gave it nothing to read.
+  returns a number, or None where the run gave it nothing to read. The
+  ``LayerContext`` it is given offers ``span_ms`` (the program's span
+  milliseconds), ``counter`` (the increase of the program's counters),
+  ``program_ms`` (device milliseconds of named programs), each per
+  traced unit, and ``device_busy_shares`` (each chip's busy share of
+  the traced window).
+
+A kind's ``build`` is handed the cell's devices, as many as its
+``chips``; one that uses one chip keeps its state on the default
+device, the first of them.
 
 A run: look for the chips, point JAX's compile cache into the checkout,
 build the cell's data from the seed and warm up its shapes (set-up),
@@ -75,25 +84,29 @@ def load_cell(workload, root=ROOT):
     return spec, cell, config, mix
 
 
-def build_system(config, mix, seed, seconds, rec, root=ROOT, path=None):
+def build_system(config, mix, seed, seconds, rec, devs, root=ROOT,
+                 path=None):
     kind = mix['requests']
     mod = _load_module(os.path.join(root, 'benchmark', 'kinds',
                                     kind + '.py'), 'bench_kind_' + kind)
-    return mod.build(config, mix, seed, seconds, rec, path=path)
+    return mod.build(config, mix, seed, seconds, rec, path=path,
+                     devices=devs)
 
 
 # -- preflight ------------------------------------------------------------------
 
-def preflight(chips):
+def preflight(chips, require_tpu=True):
     """The devices this cell runs on. Raises Failure where JAX finds no
-    TPU or fewer chips than the cell asks for."""
+    TPU (unless ``require_tpu`` is off) or fewer devices than the cell
+    asks for."""
     import jax
     devs = jax.devices()
-    if devs[0].platform != 'tpu':
+    if require_tpu and devs[0].platform != 'tpu':
         raise Failure(f'needs a TPU, but JAX found platform '
                       f'{devs[0].platform!r}; nothing ran')
     if len(devs) < chips:
-        raise Failure(f'needs {chips} TPU chips, JAX found {len(devs)}')
+        raise Failure(f'needs {chips} chips, JAX found {len(devs)} '
+                      f'{devs[0].platform} devices; nothing ran')
     return devs[:chips]
 
 
@@ -148,12 +161,14 @@ class Recorder:
     is a no-op. On (while the profiler runs), it is a
     ``jax.profiler.TraceAnnotation`` (``bench.<name>``) that names the
     device's idle gaps; the program's own span events are collected from
-    its metrics bus at the same time."""
+    its metrics bus at the same time, and ``counters`` holds how far
+    each of its counters moved over that time."""
 
     def __init__(self):
         self.on = False
         self.events = []
         self.units = 0
+        self.counters = {}
 
     @contextlib.contextmanager
     def span(self, name):
@@ -179,6 +194,7 @@ class Tracer:
         self.dir = None
         self.window = None
         self.t0 = None
+        self.counters0 = None
         self.done = False
 
     def start(self):
@@ -195,6 +211,7 @@ class Tracer:
         self.window = jax.profiler.TraceAnnotation('bench.window')
         self.window.__enter__()
         metrics.subscribe(self.rec._collect)
+        self.counters0 = metrics.snapshot()
         self.rec.on = True
         self.t0 = time.perf_counter()
 
@@ -212,7 +229,12 @@ class Tracer:
             return
         self.done = True
         self.rec.on = False
+        counters = metrics.snapshot()
         metrics.unsubscribe(self.rec._collect)
+        self.rec.counters = {
+            name: value - self.counters0.get(name, 0)
+            for name, value in counters.items()
+            if value != self.counters0.get(name, 0)}
         self.window.__exit__(None, None, None)
         jax.profiler.stop_trace()
 
@@ -333,23 +355,41 @@ def end_to_end(spec, cell, run, dev, setup_s):
 
 class LayerContext:
     """What a per-layer reader sees: the traced units, the program's
-    span totals and the reduced device trace (None where there is
-    none)."""
+    span totals and counter moves, and the reduced device trace (None
+    where there is none)."""
 
     def __init__(self, rec, trace):
         self.units = rec.units
         self.trace = trace
+        self._counters = rec.counters
         self._span_ms = {}
         for name, ms in rec.events:
             self._span_ms[name] = self._span_ms.get(name, 0.0) + ms
 
-    def span_ms(self, *names):
-        """Program span milliseconds per traced unit, summed over
-        ``names``; None where no such span was recorded."""
-        got = [self._span_ms[n] for n in names if n in self._span_ms]
+    def _per_unit(self, totals, names):
+        got = [totals[n] for n in names if n in totals]
         if not got or not self.units:
             return None
         return sum(got) / self.units
+
+    def span_ms(self, *names):
+        """Program span milliseconds per traced unit, summed over
+        ``names``; None where no such span was recorded."""
+        return self._per_unit(self._span_ms, names)
+
+    def counter(self, *names):
+        """The increase of the program's counters ``names`` over the
+        traced window, summed and per traced unit; None where none of
+        them moved."""
+        return self._per_unit(self._counters, names)
+
+    def device_busy_shares(self):
+        """Each device plane's busy seconds over the traced window's, in
+        the trace's plane order; None without a device trace."""
+        if self.trace is None or not self.trace['window_s']:
+            return None
+        return [b / self.trace['window_s']
+                for b in self.trace['busy_s_by_device']]
 
     def program_ms(self, pattern):
         """Device milliseconds per traced unit of the programs whose
@@ -382,16 +422,13 @@ def run(workload, seed, seconds, trace, t_start, root=ROOT,
     """One run of one cell; returns the result line's object. ``path``
     replaces the served path (the control and the fault tests)."""
     spec, cell, config, mix = load_cell(workload, root)
-    if require_tpu:
-        devs = preflight(cell['chips'])
-    else:
-        import jax
-        devs = jax.devices()[:cell['chips']]
+    devs = preflight(cell['chips'], require_tpu)
     use_cache(root)
     require_native()
     watch = CompileWatch()
     rec = Recorder()
-    system = build_system(config, mix, seed, seconds, rec, root, path)
+    system = build_system(config, mix, seed, seconds, rec, devs, root,
+                          path)
     system.prepare(watch)
     # the generated traffic and the preloaded state stay alive all
     # window: keep them out of the collector's scans

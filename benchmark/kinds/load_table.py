@@ -23,7 +23,8 @@ from gen import ycsb  # noqa: E402
 SAMPLE_DOCS = 64
 
 
-def build(config, mix, seed, seconds, rec, path=None):
+def build(config, mix, seed, seconds, rec, path=None, devices=None):
+    # one chip: the doc set stays on the default device, devices[0]
     return LoadTable(config, mix, seed, rec, path or served.DocSetPath())
 
 

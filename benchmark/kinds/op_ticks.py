@@ -68,7 +68,8 @@ def _renumber(updates, seqs):
     return out
 
 
-def build(config, mix, seed, seconds, rec, path=None):
+def build(config, mix, seed, seconds, rec, path=None, devices=None):
+    # one chip: the doc set stays on the default device, devices[0]
     return OpTicks(config, mix, seed, seconds, rec,
                    path or served.DocSetPath())
 

@@ -48,6 +48,16 @@ def test_cell_added_as_data_files_runs(tmp_path):
             'setup_s'} == set(out['metrics'])
 
 
+def test_counter_is_per_unit_and_silent_where_nothing_moved():
+    import harness
+    rec = harness.Recorder()
+    rec.units = 3
+    rec.counters = {'a': 6, 'b': 3}
+    ctx = harness.LayerContext(rec, None)
+    assert ctx.counter('a', 'b', 'c') == 3.0
+    assert ctx.counter('c') is None
+
+
 def test_no_tpu_no_result():
     env = dict(os.environ, JAX_PLATFORMS='cpu')
     proc = subprocess.run(

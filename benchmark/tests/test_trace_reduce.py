@@ -11,9 +11,11 @@ program falls in the ``bench.wait`` before its ``bench.apply``.
 """
 
 import os
+from types import SimpleNamespace as NS
 
 import pytest
 
+import harness
 import trace_reduce
 
 FIXTURE = os.path.join(os.path.dirname(__file__), 'fixtures',
@@ -29,6 +31,7 @@ def test_window_and_busy(reduced):
     assert reduced['devices'] == 1
     assert reduced['window_s'] == pytest.approx(85_148_574e-9, abs=1e-12)
     assert reduced['busy_s'] == pytest.approx(106_494e-9, abs=1e-12)
+    assert reduced['busy_s_by_device'] == [reduced['busy_s']]
     assert reduced['idle_share'] == pytest.approx(
         1 - 106_494 / 85_148_574, abs=1e-12)
 
@@ -57,3 +60,32 @@ def test_no_device_plane_reduces_to_none(tmp_path):
     class Empty:
         planes = []
     assert trace_reduce.reduce(Empty()) is None
+
+
+def _plane(name, *lines):
+    return NS(name=name, lines=[
+        NS(name=line, events=[NS(name=n, start_ns=s, duration_ns=d)
+                              for n, s, d in events])
+        for line, events in lines])
+
+
+def test_busy_by_device_tells_planes_apart():
+    """Two chips taking turns: one busy 150 ns early in a 1,000 ns
+    window (two overlapping ops), the other 100 ns late."""
+    profile = NS(planes=[
+        _plane('/host:CPU', ('python', [('bench.window', 0, 1000)])),
+        _plane('/device:TPU:0', ('XLA Ops', [('a', 0, 100),
+                                             ('b', 50, 100)])),
+        _plane('/device:TPU:1', ('XLA Ops', [('c', 600, 100)])),
+    ])
+    reduced = trace_reduce.reduce(profile)
+    assert reduced['devices'] == 2
+    assert reduced['busy_s_by_device'] == pytest.approx([150e-9, 100e-9],
+                                                        abs=1e-18)
+    assert sum(reduced['busy_s_by_device']) / 2 == pytest.approx(
+        reduced['busy_s'], abs=1e-18)
+    assert reduced['idle_share'] == pytest.approx(1 - 125 / 1000)
+    ctx = harness.LayerContext(harness.Recorder(), reduced)
+    assert ctx.device_busy_shares() == pytest.approx([0.15, 0.10])
+    assert harness.LayerContext(harness.Recorder(),
+                                None).device_busy_shares() is None

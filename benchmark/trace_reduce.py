@@ -4,7 +4,8 @@ One traced window gives, averaged over the device planes: the seconds in
 which some operation ran (the union of the op intervals), the idle share,
 device seconds per operation and per program, and the idle gaps, each
 named by the benchmark's own host annotation (``bench.*``) that covers
-the gap's midpoint.
+the gap's midpoint. It also gives each plane's own busy seconds, which
+tell chips busy at once from chips taking turns.
 
 The window is the span of the host annotation ``bench.window`` where the
 trace holds one, else the first to the last device event.
@@ -54,8 +55,10 @@ def load(path):
 
 
 def reduce(profile, top=10):
-    """``{'window_s', 'busy_s', 'idle_share', 'devices', 'op_s',
-    'program_s', 'gaps'}`` of one trace; ``op_s`` and ``program_s`` map
+    """``{'window_s', 'busy_s', 'busy_s_by_device', 'idle_share',
+    'devices', 'op_s', 'program_s', 'gaps'}`` of one trace; ``busy_s``
+    is the mean over the device planes of ``busy_s_by_device``, each
+    plane's busy seconds in plane order; ``op_s`` and ``program_s`` map
     a name to its device seconds summed over the device planes and
     divided by their number; ``gaps`` lists the ``top`` longest idle
     gaps as ``[name, seconds]``. Returns None where the trace holds no
@@ -89,12 +92,12 @@ def reduce(profile, top=10):
         lo, hi = min(starts), max(ends)
     spans = sorted((s, e, n) for n, s, e in host if n != WINDOW)
     n_dev = len(devices)
-    busy_ns = 0
+    busy_ns = []
     op_s, program_s, gaps = {}, {}, []
     for ops, progs in lines:
         ops = _clip(ops, lo, hi)
         merged = _union([(s, e) for _, s, e in ops])
-        busy_ns += sum(e - s for s, e in merged)
+        busy_ns.append(sum(e - s for s, e in merged))
         for name, s, e in ops:
             op_s[name] = op_s.get(name, 0.0) + (e - s) * 1e-9 / n_dev
         for name, s, e in _clip(progs, lo, hi):
@@ -107,8 +110,9 @@ def reduce(profile, top=10):
                              (e - s) * 1e-9])
     gaps.sort(key=lambda g: -g[1])
     window_s = (hi - lo) * 1e-9
-    busy_s = busy_ns * 1e-9 / n_dev
+    busy_s = sum(busy_ns) * 1e-9 / n_dev
     return {'window_s': window_s, 'busy_s': busy_s,
+            'busy_s_by_device': [ns * 1e-9 for ns in busy_ns],
             'idle_share': 1.0 - busy_s / window_s if window_s else None,
             'devices': n_dev, 'op_s': op_s, 'program_s': program_s,
             'gaps': gaps[:top]}
