@@ -321,18 +321,19 @@ class _SeqPool:
             self._host_epoch = self._epoch
             n = self.mirror['n']
             fmt = self.mirror.get('fmt')
+            # whole planes, cut on the host: a device slice to ``n``
+            # would compile once per node count, so once per tick
             if fmt == 'packed':
                 # ONE 4B/node fetch; the vis word host-unpacks for free
-                w2 = np.asarray(jax.device_get(self.mirror['w2'][:n]))
+                w2 = np.asarray(jax.device_get(self.mirror['w2']))[:n]
                 vis, idx = unpack_w2_word(w2)
             elif fmt == 'wide':
                 # same 4B/node fetch: W2 carries visible + vis_index
-                w2 = np.asarray(jax.device_get(self.mirror['w2'][:n]))
+                w2 = np.asarray(jax.device_get(self.mirror['w2']))[:n]
                 vis, idx = unpack_wide_word(w2)
             else:
-                vis, idx = jax.device_get(
-                    (self.mirror['visible'][:n],
-                     self.mirror['vis_index'][:n]))
+                vis, idx = (np.asarray(a)[:n] for a in jax.device_get(
+                    (self.mirror['visible'], self.mirror['vis_index'])))
             # the mirror's OWN pos_row snapshot: appends since the apply
             # (e.g. single obj_row creates) must not shift the mapping
             rows = self.mirror['pos_row'][:n]
@@ -2404,6 +2405,7 @@ def _pick_incremental(pool, mir, dirty, n_j, nof_pre, mel_pre, n_old,
     else:
         if len(dirty):
             metrics.bump('device_idx_rebuild_applies')
+            metrics.bump('device_idx_rebuild_nodes', int(n_j.sum()))
         if _INDEX_MODE == 'require' and len(dirty):
             # loud, with store rollback via the apply txn: an
             # invalidation path that silently falls back is a bug the
@@ -4070,6 +4072,7 @@ def _apply_general(store, block, options, return_timing, txn=None):
             metrics.bump('device_idx_invalidations')
         if len(dirty):
             metrics.bump('device_idx_rebuild_applies')
+            metrics.bump('device_idx_rebuild_nodes', int(n_j.sum()))
         surv_u8_dev, winner_dev = outs[5], outs[6]
         vis_planes = outs[7:11] if len(dirty) else None
         vis_fmt = 'cols'

@@ -227,6 +227,8 @@ CONVERGENCE_COUNTERS = (
 #                              insert, cols downgrade)
 #   device_idx_delta_nodes     total delta nodes merged by the
 #                              incremental path
+#   device_idx_rebuild_nodes   total tree nodes of the dirty objects
+#                              the rebuild applies re-ordered whole
 #   device_idx_update_ms       observe series: fenced run time of
 #                              SAMPLED incremental-index applies (the
 #                              merge pass's own phase attribution)
@@ -260,6 +262,7 @@ DEVICE_COUNTERS = (
     'device_run_ms', 'device_patch_read_ms',
     'device_idx_incremental_applies', 'device_idx_rebuild_applies',
     'device_idx_invalidations', 'device_idx_delta_nodes',
+    'device_idx_rebuild_nodes',
     'device_idx_update_ms', 'device_idx_window_applies',
     'device_stage_cache_hits', 'device_stage_cache_misses',
     'device_utilization',
